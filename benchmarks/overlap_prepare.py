@@ -39,6 +39,12 @@ permit — the honest yardstick for whether *the overlap machinery*
 (rather than the hypervisor) is stealing serving cycles. Both numbers
 land in the artifact (``parallel_headroom`` = capacity / steady).
 
+CPU only. The compile server is a second process that imports JAX; on an
+accelerator the parent already holds the device and a child that needs
+it would fail or hang, so the benchmark refuses any backend but the CPU.
+Its two compile caches live at fixed paths under ``benchmarks/scratch/``
+(gitignored) and are emptied at start.
+
 Emits ``name,value,derived`` CSV rows and returns the JSON-able dict CI
 writes to ``benchmarks/BENCH_overlap.json``.
 """
@@ -47,17 +53,24 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import threading
 import time
+from pathlib import Path
+
+# The compile caches: fixed paths inside the checkout (gitignored), so a
+# cache key never depends on a temporary name; emptied at start.
+SCRATCH = Path(__file__).resolve().parent / "scratch"
+CACHE_DIR = SCRATCH / "overlap_jaxcache"
+CALIB_CACHE_DIR = SCRATCH / "overlap_calib_jaxcache"
 
 # The COMPILE SERVER: a resident child process that pays the jax import +
-# model build once at startup (amortized across every swap, like a
-# production compile daemon), then compiles the modules of each request
-# line — the same modules `ServingEngine.aot_executables` will lower
-# (identical ShapeDtypeStructs and shardings -> identical
+# engine build once at startup (amortized across every swap, like a
+# production compile daemon), then runs `ServingEngine.aot_executables`
+# for each request line — the very modules the parent's PREPARE will
+# lower (identical ShapeDtypeStructs and shardings -> identical
 # persistent-cache keys), so the parent's in-process compile becomes a
 # cache hit. Protocol: prints "ready" after boot, then one "done" line
 # per JSON request line on stdin.
@@ -70,28 +83,18 @@ jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 import dataclasses
 import numpy as np
-import jax.numpy as jnp
 from repro.configs import get_reduced_config
 from repro.models import build_model
+from repro.serving import ServingEngine
 from repro.sharding import ShardingPlan, plan_to_shardings
 
 cfg = dataclasses.replace(get_reduced_config(boot["arch"]),
                           param_dtype="float32", activ_dtype="float32")
 model = build_model(cfg)
+engine = ServingEngine(model, model.init_params(jax.random.PRNGKey(0)),
+                       n_slots=boot["n_slots"], s_max=boot["s_max"])
 mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1, 1),
                          ("pod", "data", "model"))
-n_slots, s_max = boot["n_slots"], boot["s_max"]
-sds = jax.ShapeDtypeStruct
-p_shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-c_shapes = jax.eval_shape(lambda: model.init_cache(n_slots, s_max))
-
-def batch_sds(S, padded):
-    b = {"tokens": sds((1, S), jnp.int32)}
-    if padded:
-        b["true_len"] = sds((), jnp.int32)
-    if cfg.pos_type == "mrope":
-        b["positions"] = sds((3, 1, S), jnp.int32)
-    return b
 
 print("ready", flush=True)
 for line in sys.stdin:
@@ -99,18 +102,9 @@ for line in sys.stdin:
     plan = ShardingPlan(
         device_constraints=tuple(tuple(p) for p in req["pins"]),
         forbidden_collective_axes=tuple(req["forbidden"]))
-    sh = plan_to_shardings(cfg, plan, mesh, n_slots=n_slots)
-    p_sds = jax.tree.map(lambda x, s: sds(x.shape, x.dtype, sharding=s),
-                         p_shapes, sh["params"])
-    c_sds = jax.tree.map(lambda x, s: sds(x.shape, x.dtype, sharding=s),
-                         c_shapes, sh["cache"])
-    jax.jit(model.decode_step, donate_argnums=(2,)).lower(
-        p_sds, sds((n_slots, 1), jnp.int32), c_sds,
-        sds((n_slots,), jnp.int32)).compile()
-    for S in req["prefill_lengths"]:
-        jax.jit(model.prefill).lower(p_sds, batch_sds(S, False)).compile()
-    for S in req["bucket_lengths"]:
-        jax.jit(model.prefill).lower(p_sds, batch_sds(S, True)).compile()
+    sh = plan_to_shardings(cfg, plan, mesh, n_slots=engine.cache_batch)
+    engine.aot_executables(sh, prefill_lengths=req["prefill_lengths"],
+                           prefill_buckets=req["buckets"])
     print("done", flush=True)
 '''
 
@@ -128,13 +122,13 @@ class _WarmServer:
         assert self.proc.stdout.readline().strip() == "ready", \
             "compile server failed to boot"
 
-    def request(self, prefill_lengths, bucket_lengths=(), pins=(),
+    def request(self, prefill_lengths, buckets=False, pins=(),
                 forbidden=()):
         """Ask the server to compile one module set; blocks until done
         (call from a worker thread to overlap with serving)."""
         self.proc.stdin.write(json.dumps({
             "prefill_lengths": list(prefill_lengths),
-            "bucket_lengths": list(bucket_lengths),
+            "buckets": buckets,
             "pins": [list(p) for p in pins],
             "forbidden": list(forbidden)}) + "\n")
         reply = self.proc.stdout.readline().strip()
@@ -145,20 +139,22 @@ class _WarmServer:
         self.proc.wait()
 
 
+def _fresh_dir(path: Path) -> str:
+    shutil.rmtree(path, ignore_errors=True)
+    path.mkdir(parents=True)
+    return str(path)
+
+
 def _enable_compile_cache(cache_dir: str) -> None:
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    try:
-        # the cache singleton latches on first use: when another benchmark
-        # already compiled in this process, config alone is a no-op and
-        # the warm subprocess' entries would never be read — force re-init
-        from jax._src.compilation_cache import reset_cache
-        reset_cache()
-    except ImportError:                    # private API moved: standalone
-        pass                               # runs still work (cache set
-                                           # before the first compile)
+    # the cache singleton latches on first use: when another benchmark
+    # already compiled in this process, config alone is a no-op and the
+    # warm subprocess' entries would never be read — force re-init
+    compilation_cache.reset_cache()
 
 
 def bench_overlap_prepare(arch: str = "minitron_4b",
@@ -175,9 +171,15 @@ def bench_overlap_prepare(arch: str = "minitron_4b",
         def emit(name, value, derived=""):
             print(f"{name},{value},{derived}")
 
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            "overlap_prepare is CPU-only: its compile server is a second "
+            "process that imports JAX, and on "
+            f"{jax.default_backend()!r} this process already holds the "
+            "device — run it with JAX_PLATFORMS=cpu")
     budget_s = float(os.environ.get("DOWNTIME_BUDGET_S", "0.05"))
     tol = float(os.environ.get("OVERLAP_TOL", "0.10"))
-    cache_dir = tempfile.mkdtemp(prefix="bench_overlap_jaxcache_")
+    cache_dir = _fresh_dir(CACHE_DIR)
     _enable_compile_cache(cache_dir)
 
     n_slots, s_max = 16, 48
@@ -254,9 +256,8 @@ def bench_overlap_prepare(arch: str = "minitron_4b",
     # boot both compile servers BEFORE the measured phases: a resident
     # compile daemon pays jax import + model build once, not per swap
     warm_server = _WarmServer(arch, n_slots, s_max, cache_dir, env)
-    calib_server = _WarmServer(
-        arch, n_slots, s_max,
-        tempfile.mkdtemp(prefix="bench_overlap_calib_"), env)
+    calib_server = _WarmServer(arch, n_slots, s_max,
+                               _fresh_dir(CALIB_CACHE_DIR), env)
 
     # ---- steady state: the trace with no reconfiguration ----
     load(n_requests)
@@ -307,10 +308,8 @@ def bench_overlap_prepare(arch: str = "minitron_4b",
     # ---- overlapped: trace + concurrent reconfigure (warmed PREPARE) ----
     pinned = ShardingPlan(device_constraints=(("pod", 0),),
                           forbidden_collective_axes=("pod",))
-    buckets = cluster.engine("e0").bucket_lengths()
-
     def warm():
-        warm_server.request(lengths, buckets, pinned.device_constraints,
+        warm_server.request(lengths, True, pinned.device_constraints,
                             pinned.forbidden_collective_axes)
 
     load(n_requests)

@@ -1,9 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python op-by-op, which validates BlockSpec indexing and the
-online-softmax/recurrence logic. On TPU the same call sites compile to
-Mosaic.
+On TPU the kernels compile to Mosaic. On CPU they execute in interpret
+mode — the kernel body runs op-by-op, which validates BlockSpec indexing
+and the online-softmax/recurrence logic but not what Mosaic accepts (see
+tests/test_tpu_compile.py for that). Any other backend is refused: a
+kernel is never silently interpreted where it was meant to compile.
 """
 from __future__ import annotations
 
@@ -18,7 +19,13 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels compile on TPU or interpret on CPU; "
+                       f"backend {backend!r} is neither")
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "q_block", "k_block"))

@@ -25,8 +25,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref,
-                *, chunk: int):
+def _ssd_kernel(x_ref, dt_ref, dtr_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
+                h_ref, *, chunk: int):
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -36,19 +36,27 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref,
 
     x = x_ref[0, 0, 0].astype(jnp.float32)     # (L, P)
     dt = dt_ref[0, 0, 0].astype(jnp.float32)   # (L, 1) — keep 2D for TPU
-    A = a_ref[...]                              # (1,) fp32
+    dt_row = dtr_ref[0, 0, 0].astype(jnp.float32)   # (1, L) — same values
+    A = a_ref[pl.program_id(1)]                 # this head's A (SMEM scalar)
     Bm = b_ref[0, 0, 0].astype(jnp.float32)    # (L, N)
     Cm = c_ref[0, 0, 0].astype(jnp.float32)    # (L, N)
 
     L = x.shape[0]
-    dA = dt[:, 0] * A[0]                        # (L,)
-    dA_cum = jnp.cumsum(dA)                     # (L,)
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    # inclusive prefix sums of dA as triangular matmuls (Mosaic has no
+    # cumsum), once as a column and once as a row, so the (L, L) segment
+    # sums below need no transpose
+    tril = (row >= col).astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    dA = dt * A                                               # (L, 1)
+    dA_cum = jnp.dot(tril, dA, precision=hi)                  # (L, 1)
+    dA_cum_row = jax.lax.dot_general(
+        dt_row * A, tril, (((1,), (1,)), ((), ())), precision=hi)  # (1, L)
 
     # decay kernel: exp(segsum) lower-triangular
     # segsum convention: sum_{j < t <= i} dA_t = dA_cum[i] - dA_cum[j]
-    seg = dA_cum[:, None] - dA_cum[None, :]
-    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    seg = dA_cum - dA_cum_row
     decay = jnp.where(row >= col, jnp.exp(seg), 0.0)        # (L, L)
 
     dtx = x * dt                                            # (L, P)
@@ -61,7 +69,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref,
 
     # inter-chunk contribution from carried state
     h_prev = h_ref[...]                                     # (P, N)
-    state_decay = jnp.exp(dA_cum)[:, None]                  # (L, 1)
+    state_decay = jnp.exp(dA_cum)                           # (L, 1)
     y_off = jax.lax.dot_general(Cm * state_decay, h_prev,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)   # (L, P)
@@ -69,8 +77,13 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref,
     y_ref[0, 0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
     # state update: h = h * exp(sum dA) + (decay_states * dtx)^T B
-    chunk_decay = jnp.exp(dA_cum[L - 1])
-    decay_states = jnp.exp(dA_cum[L - 1] - dA_cum)[:, None]  # (L, 1)
+    # the chunk total and the suffix sums sum_{t > i} dA_t, again as
+    # matmuls: Mosaic cannot broadcast one element of a vector both ways
+    P = x.shape[1]
+    chunk_decay = jnp.exp(jnp.dot(jnp.ones((P, L), jnp.float32), dA,
+                                  precision=hi))            # (P, 1)
+    decay_states = jnp.exp(jnp.dot((col > row).astype(jnp.float32), dA,
+                                   precision=hi))           # (L, 1)
     hb = jax.lax.dot_general(dtx * decay_states, Bm,
                              (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)      # (P, N)
@@ -108,6 +121,7 @@ def ssd_scan(
     # layouts: (B, H, nc, L, ...) so blocks are contiguous per grid row
     xh = jnp.moveaxis(x, 2, 1).reshape(Bb, H, nc, chunk, P)
     dth = jnp.moveaxis(dt, 2, 1).reshape(Bb, H, nc, chunk, 1).astype(jnp.float32)
+    dth_row = dth.reshape(Bb, H, nc, 1, chunk)
     bh = jnp.moveaxis(B_mat, 2, 1).reshape(Bb, G, nc, chunk, N)
     ch = jnp.moveaxis(C_mat, 2, 1).reshape(Bb, G, nc, chunk, N)
 
@@ -120,7 +134,10 @@ def ssd_scan(
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, P), lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, 1, 1, chunk), lambda b, h, c: (b, h, c, 0, 0)),
+            # A is read per head as a scalar: the whole (H,) vector sits
+            # in SMEM (a rank-1 VMEM block of 1 is not a legal TPU tile)
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, 1, chunk, N),
                          lambda b, h, c, rep=rep: (b, h // rep, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, N),
@@ -136,7 +153,7 @@ def ssd_scan(
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(xh, dth, A.astype(jnp.float32), bh, ch)
+    )(xh, dth, dth_row, A.astype(jnp.float32), bh, ch)
     y = y.reshape(Bb, H, S, P)
     y = jnp.moveaxis(y, 1, 2)[:, :S_orig]                   # (B, S, H, P)
     return y, h_final

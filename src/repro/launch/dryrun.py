@@ -159,8 +159,6 @@ def lower_cell(arch: str, shape: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     xla_cost = compiled.cost_analysis()
-    if isinstance(xla_cost, (list, tuple)):
-        xla_cost = xla_cost[0] if xla_cost else {}
     hlo = compiled.as_text()
     # trip-count-aware cost model (XLA's cost_analysis counts while bodies
     # once — useless for scan-over-layers; see repro.core.hlo_cost)

@@ -1035,15 +1035,24 @@ class ServingCluster:
             # the blocking swap window migrates the live trees with
             # these exact transfers and must not pay their first-call
             # compile — the same compile-ahead discipline the
-            # executables get. Probe trees are freed immediately.
+            # executables get. A leaf already laid out as the target
+            # needs no transfer (device_put aliases it); the others are
+            # probed ONE leaf at a time from the live leaf's layout, so
+            # the probe never holds a second copy of the weights.
             import jax.numpy as jnp
             for key, tree in (("params", engine.params),
                               ("cache", engine.cache)):
-                if key in sh:
-                    probe = jax.device_put(
-                        jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype),
-                                     tree), sh[key])
-                    jax.block_until_ready(jax.tree.leaves(probe))
+                if key not in sh:
+                    continue
+                for leaf, target in zip(jax.tree.leaves(tree),
+                                        jax.tree.leaves(sh[key])):
+                    src = getattr(leaf, "sharding", None)
+                    if src is not None and src.is_equivalent_to(target,
+                                                                leaf.ndim):
+                        continue
+                    probe = jax.device_put(jnp.zeros(leaf.shape, leaf.dtype),
+                                           src)
+                    jax.block_until_ready(jax.device_put(probe, target))
                     del probe
             executables, n_compiled = engine.aot_executables(
                 sh, prefill_lengths=lengths,
@@ -1068,7 +1077,11 @@ class ServingCluster:
             eng = entry.engine
             # snapshot on THIS thread: the worker must never iterate the
             # live seen-lengths dict while request threads mutate it
-            lengths = tuple(prefill_lengths) or eng.recent_prompt_lengths()
+            # a swap never drops the bucket ladder the engine serves with
+            # (unseen prompt lengths would JIT on the serving path)
+            prefill_buckets = prefill_buckets or eng.has_prefill_buckets
+            lengths = tuple(prefill_lengths) or (
+                () if prefill_buckets else eng.recent_prompt_lengths())
             ticket = PrepareTicket(name, "reconfigure", plan)
             if entry.pending_ticket is not None:
                 # a newer plan supersedes the old pending swap — its

@@ -35,6 +35,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.models import Model
 from repro.obs import events as obs_events
@@ -230,6 +231,8 @@ class ServingEngine:
             self._tables_dev: Optional[jnp.ndarray] = None
             self._paged_fn = kvpool.make_paged_decode(model, self._pax,
                                                       self._sax)
+            self._paged_prefill_fn = kvpool.make_paged_prefill(
+                model, self._pax, self._sax)
         else:
             self.pool = None
             self.cache = model.init_cache(n_slots, s_max)
@@ -242,8 +245,11 @@ class ServingEngine:
         self.seen_prompt_lengths: Dict[int, int] = {}   # length -> last seq
         self._submit_seq = 0
         # jitted single-sequence prefill + batched decode (JIT fallbacks);
-        # AOT executables, when installed via swap_plan, take precedence
-        self._prefill = jax.jit(model.prefill)
+        # AOT executables, when installed via swap_plan, take precedence.
+        # A paged engine's prefill also writes the request's pages
+        # (`kvpool.make_paged_prefill`; the store is donated)
+        self._prefill = (jax.jit(self._paged_prefill_fn, donate_argnums=(2,))
+                         if self.paged else jax.jit(model.prefill))
         self._decode = (jax.jit(self._paged_fn, donate_argnums=(2,))
                         if self.paged
                         else jax.jit(model.decode_step, donate_argnums=(2,)))
@@ -313,7 +319,9 @@ class ServingEngine:
                 plain callable replaces the JIT fallback; an AOT
                 dict/executable is installed ahead of the fallback;
                 bucket executables serve unseen prompt lengths padded to
-                the bucket (see `aot_executables`).
+                the bucket (see `aot_executables`). A paged engine's
+                prefill callables take the fused paged signature
+                (`kvpool.make_paged_prefill`).
 
         Returns:
             The number of bytes migrated (0 without ``shardings``).
@@ -408,6 +416,14 @@ class ServingEngine:
         out.append(self.s_max)
         return out
 
+    @property
+    def has_prefill_buckets(self) -> bool:
+        """Whether a padded-bucket prefill ladder is installed (a
+        reconfigure keeps it, so unseen prompt lengths never fall back
+        to JIT after a swap)."""
+        with self._exec_lock:
+            return bool(self._bucket_lengths)
+
     def aot_executables(self, shardings: Dict[str, Any],
                         prefill_lengths: Sequence[int] = (), *,
                         prefill_buckets: bool = False,
@@ -420,7 +436,8 @@ class ServingEngine:
                 sharding trees (see `plan_to_shardings`).
             prefill_lengths: prompt lengths to compile prefill for; when
                 empty, falls back to the engine's most recently seen
-                lengths (capped at `MAX_AOT_PREFILL`).
+                lengths (capped at `MAX_AOT_PREFILL`) — unless a bucket
+                ladder is compiled, which serves every length already.
             prefill_buckets: also compile padded-bucket prefill
                 executables (`bucket_lengths`) that take a ``true_len``
                 argument, so prompt lengths never seen before ALSO avoid
@@ -440,12 +457,18 @@ class ServingEngine:
                              self.cache, shardings["cache"])
         tok_sds = sds((self.n_slots, 1), jnp.int32)
         pos_sds = sds((self.n_slots,), jnp.int32)
+        # the donated store leaves each step exactly as it came in (left
+        # to propagation, a multi-device layout could drift and the next
+        # call would no longer match its executable); logits replicate
+        out_sh = (_replicated(shardings["cache"]), shardings["cache"])
         if self.paged:
             tbl_sds = sds((self.n_slots, self.pages_per_seq), jnp.int32)
-            decode = jax.jit(self._paged_fn, donate_argnums=(2,)) \
+            decode = jax.jit(self._paged_fn, donate_argnums=(2,),
+                             out_shardings=out_sh) \
                 .lower(p_sds, tok_sds, c_sds, pos_sds, tbl_sds).compile()
         else:
-            decode = jax.jit(self.model.decode_step, donate_argnums=(2,)) \
+            decode = jax.jit(self.model.decode_step, donate_argnums=(2,),
+                             out_shardings=out_sh) \
                 .lower(p_sds, tok_sds, c_sds, pos_sds).compile()
         n_compiled = 1
 
@@ -457,21 +480,34 @@ class ServingEngine:
                 b["positions"] = sds((3, 1, S), jnp.int32)
             return b
 
+        if self.paged:
+            row_sds = sds((self.pages_per_seq,), jnp.int32)
+            prefill_jit = jax.jit(self._paged_prefill_fn, donate_argnums=(2,),
+                                  out_shardings=out_sh)
+
+            def compile_prefill(S: int, padded: bool):
+                return prefill_jit.lower(p_sds, batch_sds(S, padded),
+                                         c_sds, row_sds).compile()
+        else:
+            def compile_prefill(S: int, padded: bool):
+                return jax.jit(self.model.prefill) \
+                    .lower(p_sds, batch_sds(S, padded)).compile()
+
         prefill: Dict[int, Callable] = {}
         if prefill_lengths:
             lengths = sorted(set(prefill_lengths))
+        elif prefill_buckets and self.bucket_lengths():
+            lengths = []      # the ladder already serves every length
         else:
             # most recently seen distinct lengths, capped (see MAX_AOT_PREFILL)
             lengths = list(self.recent_prompt_lengths())
         for S in lengths:
-            prefill[S] = jax.jit(self.model.prefill) \
-                .lower(p_sds, batch_sds(S, padded=False)).compile()
+            prefill[S] = compile_prefill(S, padded=False)
             n_compiled += 1
         buckets: Dict[int, Callable] = {}
         if prefill_buckets:
             for S in self.bucket_lengths():
-                buckets[S] = jax.jit(self.model.prefill) \
-                    .lower(p_sds, batch_sds(S, padded=True)).compile()
+                buckets[S] = compile_prefill(S, padded=True)
                 n_compiled += 1
         return {"prefill": prefill, "decode": decode,
                 "prefill_buckets": buckets}, n_compiled
@@ -650,7 +686,9 @@ class ServingEngine:
                     return    # fail closed: stays queued, FIFO order kept
             req = self.queue.pop(0)
             S = len(req.prompt)
-            prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            # inputs are built on the host (numpy): an eager device op
+            # here would compile per prompt length on the serving path
+            prompt = np.asarray(req.prompt, np.int32)[None, :]
             # exact-length AOT executable first; else the smallest padded
             # bucket that holds the prompt; JIT fallback last. Selected
             # under the exec lock: a background PREPARE commit must never
@@ -662,19 +700,31 @@ class ServingEngine:
                     bucket = next((b for b in self._bucket_lengths
                                    if b >= S), None)
                     if bucket is not None:
-                        batch = {"tokens": jnp.pad(
+                        batch = {"tokens": np.pad(
                                      prompt, ((0, 0), (0, bucket - S))),
-                                 "true_len": jnp.asarray(S, jnp.int32)}
+                                 "true_len": np.int32(S)}
                         prefill = self._bucket_exec[bucket]
                     else:
                         prefill = self._prefill
             if self.model.cfg.pos_type == "mrope":
                 Sp = batch["tokens"].shape[1]
-                batch["positions"] = jnp.broadcast_to(
-                    jnp.arange(Sp, dtype=jnp.int32)[None, None], (3, 1, Sp))
+                batch["positions"] = np.broadcast_to(
+                    np.arange(Sp, dtype=np.int32)[None, None], (3, 1, Sp))
+            if self.paged:
+                # the page-table row is final before prefill: the fused
+                # paged prefill writes the cache straight into these
+                # pages; the scratch-padded tail absorbs bucket slack
+                # (never read: decode masks by position)
+                row = pages + [kvpool.SCRATCH_PAGE] \
+                    * (self.pages_per_seq - len(pages))
             t_pre0 = obs_events.now() if rec is not None else 0.0
-            logits, cache1 = prefill(self.params, batch)
-            tok = int(jnp.argmax(logits[0, : self.vocab]))
+            if self.paged:
+                logits, self.cache = prefill(self.params, batch, self.cache,
+                                             np.asarray(row, np.int32))
+            else:
+                logits, cache1 = prefill(self.params, batch)
+            tok = int(np.argmax(
+                np.asarray(logits)[0, : self.vocab].astype(np.float32)))
             t_pre1 = obs_events.now() if rec is not None else 0.0
             req.tokens_out.append(tok)
             req.t_first = time.time()
@@ -686,13 +736,6 @@ class ServingEngine:
                          prefill_s=max(0.0, t_pre1 - t_pre0),
                          role=self.role)
             if self.paged:
-                # scatter the single-sequence cache into the reserved
-                # pages; the scratch-padded table tail absorbs bucket
-                # slack (never read: decode masks by position)
-                row = pages + [kvpool.SCRATCH_PAGE] \
-                    * (self.pages_per_seq - len(pages))
-                self.cache = kvpool.write_pages(self.cache, cache1, row,
-                                                self._pax, self._sax)
                 self.page_tables[slot] = row
                 self.slot_pages[slot] = pages
                 self._tables_dev = None
@@ -857,8 +900,8 @@ class ServingEngine:
             kv_fitted: the snapshot's KV already fitted to this engine's
                 `single_layout` and placed on its sharding — the batched
                 multi-request transfer (`migration.migrate_many`) does
-                one device_put for the whole batch and hands each
-                request its slice here.
+                one device_put for the whole cohort and hands each
+                request its placed tree here.
 
         Returns:
             KV bytes written into the pool (0 for a queued snapshot).
@@ -949,7 +992,9 @@ class ServingEngine:
         else:
             logits, self.cache = decode(self.params, jnp.asarray(tokens),
                                         self.cache, pos)
-        logits = np.asarray(logits[:, : self.vocab])
+        # vocab slice and argmax on the host: no eager device op (and so
+        # no compile) on the serving path
+        logits = np.asarray(logits)[:, : self.vocab].astype(np.float32)
         now = time.time()
         rec = obs_events.RECORDER
         for i in active:
@@ -990,6 +1035,14 @@ class ServingEngine:
     def metrics(self) -> Dict[str, float]:
         """Full `METRIC_KEYS` summary over everything completed so far."""
         return compute_metrics(self.done)
+
+
+def _replicated(shardings: PyTree) -> Any:
+    """A fully replicated sharding on the devices of a sharding tree."""
+    leaf = jax.tree.leaves(shardings)[0]
+    if isinstance(leaf, NamedSharding):
+        return NamedSharding(leaf.mesh, PartitionSpec())
+    return leaf
 
 
 def _tree_bytes(tree: PyTree) -> int:

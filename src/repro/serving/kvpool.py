@@ -298,7 +298,8 @@ def write_pages(store: PyTree, single: PyTree, pages: Sequence[int],
     dim is padded/truncated to ``len(pages) * page_size``, split into
     page-sized rows, and scattered. Entries of ``pages`` equal to
     `SCRATCH_PAGE` absorb the slack (import writes full-width tables
-    whose tail is scratch — shape-static, one compiled op).
+    whose tail is scratch — shape-static, one compiled op). ``pages``
+    may be a traced ``(n,)`` int32 array (see `make_paged_prefill`).
     """
     pages_arr = jnp.asarray(pages, jnp.int32)
     n = len(pages)
@@ -339,3 +340,20 @@ def make_paged_decode(model, pax: PyTree, sax: PyTree):
         return logits, scatter_token(store, dense, tables, pos, pax, sax)
 
     return paged_decode
+
+
+def make_paged_prefill(model, pax: PyTree, sax: PyTree):
+    """The fused paged prefill (the engine's per-prompt-shape AOT unit):
+    run the model's single-sequence ``prefill`` and write its cache into
+    the request's pages inside the same executable, so an admission
+    runs no eager device op that could compile on the serving path.
+
+    Signature (store at position 2, donated like decode's):
+    ``(params, batch, store, row (pages_per_seq,)) -> (logits, new_store)``.
+    """
+
+    def paged_prefill(params, batch, store, row):
+        logits, single = model.prefill(params, batch)
+        return logits, write_pages(store, single, row, pax, sax)
+
+    return paged_prefill

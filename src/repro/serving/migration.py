@@ -14,9 +14,9 @@ the state-transfer primitive (FlexPipe-style inflight refactoring):
              ``phase="queued"`` snapshots (no KV yet).
     reshard  `fit_single` reshapes the snapshot onto the target pool's
              single-sequence layout (differing ``s_max`` pads/truncates);
-             `place_like` `jax.device_put`s each leaf onto the target
-             pool's sharding (specs that do not divide the slice shape
-             degrade to replication on that dim).
+             `place_like` `jax.device_put`s the snapshot onto the target
+             pool's sharding in one transfer call (specs that do not
+             divide the slice shape degrade to replication on that dim).
     import   `ServingEngine.import_slot(snapshot)` writes the KV into a
              free slot and resumes decode at the snapshot position — no
              recompilation (decode is shape-static) and no re-run of
@@ -50,7 +50,6 @@ from typing import TYPE_CHECKING, Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.obs import events as obs_events
 
@@ -225,33 +224,33 @@ def fit_single(kv: PyTree, dst_single: PyTree) -> PyTree:
 
 
 def place_like(kv: PyTree, pool: PyTree) -> PyTree:
-    """`jax.device_put` each snapshot leaf onto the target pool's sharding.
+    """`jax.device_put` a snapshot onto the target pool's sharding, in
+    ONE transfer call for the whole tree (``kv`` and ``pool`` may be
+    lists of congruent trees — a migration cohort).
 
     The pool's `NamedSharding` specs are re-derived for the slice shape:
     a spec entry whose mesh-axis extent does not divide the slice dim
     (e.g. a sharded batch dim collapsed to 1) degrades to replication on
-    that dim, so the transfer is always expressible."""
+    that dim, so the transfer is always expressible. No device program
+    runs, so placing never compiles."""
     from jax.sharding import NamedSharding, PartitionSpec
 
-    def one(k, p):
-        sh = getattr(p, "sharding", None)
-        if isinstance(sh, NamedSharding):
-            parts = []
-            for ax in range(k.ndim):
-                entry = sh.spec[ax] if ax < len(sh.spec) else None
-                names = entry if isinstance(entry, (tuple, list)) else (
-                    (entry,) if entry is not None else ())
-                size = 1
-                for nm in names:
-                    size *= sh.mesh.shape[nm]
-                parts.append(entry if k.shape[ax] % size == 0 else None)
-            return jax.device_put(k, NamedSharding(sh.mesh,
-                                                   PartitionSpec(*parts)))
-        if sh is not None:
-            return jax.device_put(k, sh)
-        return jnp.asarray(k)
+    def sharding_for(k, p):
+        sh = p.sharding
+        if not isinstance(sh, NamedSharding):
+            return sh
+        parts = []
+        for ax in range(k.ndim):
+            entry = sh.spec[ax] if ax < len(sh.spec) else None
+            names = entry if isinstance(entry, (tuple, list)) else (
+                (entry,) if entry is not None else ())
+            size = 1
+            for nm in names:
+                size *= sh.mesh.shape[nm]
+            parts.append(entry if k.shape[ax] % size == 0 else None)
+        return NamedSharding(sh.mesh, PartitionSpec(*parts))
 
-    return jax.tree.map(one, kv, pool)
+    return jax.device_put(kv, jax.tree.map(sharding_for, kv, pool))
 
 
 def write_single(pool: PyTree, single: PyTree, axes: PyTree,
@@ -339,10 +338,10 @@ def migrate_many(src_engine, dst_engine, rids: Sequence[int], *,
     request (`ServingCluster.migrate_requests` calls this).
 
     Pipeline: export every snapshot, fit each decoding snapshot onto the
-    destination's single-sequence layout, CONCATENATE them along the
-    batch axis, place the whole batch on the destination's sharding in
-    one transfer, then slice per request and import. The per-request
-    ``pause_s`` is honest under batching: each request's own export +
+    destination's single-sequence layout, place the whole cohort on the
+    destination's sharding in one batched transfer, then import each
+    request. The per-request ``pause_s`` is honest under batching: each
+    request's own export +
     import window plus a ``1/batch`` share of the shared transfer (the
     batching is exactly what makes the shared window small).
 
@@ -363,35 +362,13 @@ def migrate_many(src_engine, dst_engine, rids: Sequence[int], *,
     """
     # Empty cohort (every candidate filtered out upstream, e.g. by route
     # predicates): nothing pauses, nothing moves — return before any
-    # warm-up or telemetry so no degenerate batch record or pause span
-    # is ever emitted for a migration that did not happen.
+    # telemetry so no degenerate batch record or pause span is ever
+    # emitted for a migration that did not happen.
     if not rids:
         return []
-    # Warm everything that can compile BEFORE the first export, while the
-    # requests are still live and serving: the destination layout/axes
-    # lookups and — for cohorts of 2+ — the per-request batched gather
-    # (its first use at a new cohort shape costs ~200 ms of XLA compile,
-    # which would otherwise land inside the shared transfer window that
-    # pause_s shares out across the cohort).
-    n_dec = sum(1 for rid in rids
-                if any(r is not None and r.rid == rid
-                       for r in src_engine.slot_req))
-    layout = axes = None
-    if n_dec:
-        layout = dst_engine.single_layout()
-        axes = dst_engine._migration_axes()
-        if n_dec > 1:
-            dummy = jax.tree.map(
-                lambda ax, l: (np.zeros(
-                    l.shape[:ax] + (n_dec,) + l.shape[ax + 1:],
-                    dtype=l.dtype) if ax >= 0 else l),
-                axes, layout)
-            warm = place_like(dummy, dst_engine.cache)
-            warm = jax.tree.map(
-                lambda ax, b: (jnp.take(b, jnp.asarray([0], jnp.int32),
-                                        axis=ax) if ax >= 0 else b),
-                axes, warm)
-            jax.block_until_ready(jax.tree.leaves(warm))
+    # the destination layout lookup (eval_shape) happens BEFORE the
+    # first export, while the requests are still live and serving
+    layout = dst_engine.single_layout()
 
     snaps: List[SlotSnapshot] = []
     t_export: Dict[int, float] = {}
@@ -413,35 +390,15 @@ def migrate_many(src_engine, dst_engine, rids: Sequence[int], *,
     t_share = 0.0
     if decoding:
         t0 = time.perf_counter()
-        if layout is None:             # unreachable unless phases shifted
-            layout = dst_engine.single_layout()   # between count and export
-            axes = dst_engine._migration_axes()
         fits = [fit_single(s.kv, layout) for s in decoding]
-        if len(fits) == 1:
-            batched = fits[0]
-        else:
-            # concatenate on the HOST: np.concatenate never compiles, so
-            # the pause window stays compile-free for ANY cohort size
-            # (an XLA concat/slice would build one executable per batch
-            # size and per index — all inside the measured pause)
-            batched = jax.tree.map(
-                lambda ax, *ls: (np.concatenate(
-                    [np.asarray(l) for l in ls], axis=ax)
-                    if ax >= 0 else ls[0]),
-                axes, *fits)
-        placed = place_like(batched, dst_engine.cache)   # ONE device_put
+        # ONE device_put for the whole cohort (a list of per-request
+        # trees): a batched transfer that runs no device program, so the
+        # pause window stays compile-free for ANY cohort size and the KV
+        # never round-trips through the host
+        placed = place_like(fits, [dst_engine.cache] * len(fits))
         jax.block_until_ready(jax.tree.leaves(placed))
-        for i, s in enumerate(decoding):
-            if len(decoding) == 1:
-                fitted[s.rid] = placed
-            else:
-                # index passed as device DATA, not a baked constant: one
-                # gather executable per leaf shape serves every i
-                idx = jnp.asarray([i], dtype=jnp.int32)
-                fitted[s.rid] = jax.tree.map(
-                    lambda ax, b: (jnp.take(b, idx, axis=ax)
-                                   if ax >= 0 else b),
-                    axes, placed)
+        for s, kv in zip(decoding, placed):
+            fitted[s.rid] = kv
         t_share = (time.perf_counter() - t0) / len(decoding)
 
     records: List[MigrationRecord] = []
