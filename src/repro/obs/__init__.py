@@ -5,8 +5,10 @@ Four pieces behind one handle (`Recorder`):
     events.py   structured event bus — typed `Event`s, timestamped on
                 the INSTALLED serving clock (non-advancing reads under
                 a `FakeClock`), bounded ring with drop counters;
-    trace.py    nested span tracing + Chrome ``trace_event`` exporter
-                (open any replay in Perfetto);
+    trace.py    profiler annotations (`annotate`: the serving step's
+                spans in the device trace), nested span tracing +
+                Chrome ``trace_event`` exporter (open any replay in
+                Perfetto);
     metrics.py  counters / gauges / log-bucketed quantile sketches with
                 label sets, plus the `RequestAggregate` that gives
                 `ServingCluster.metrics_by_label` O(1) accounting;
@@ -23,8 +25,10 @@ Plus the Watchtower layer on top (PR 10):
                 estimator-drift alarms, liveness watchdogs, and
                 deterministic debug bundles on every fired alert.
 
-Recording is opt-in and zero-overhead when off: the serving stack
-guards every hook with ``RECORDER is None``. Enable with::
+Recording is opt-in and costs one attribute read when off: the serving
+stack guards every hook with ``RECORDER is None``. The serving step's
+profiler spans (`annotate`) are always on, about a microsecond each
+with no profiler session. Enable recording with::
 
     from repro.obs import Recorder, recording
     with recording(Recorder()) as rec:
@@ -67,6 +71,7 @@ from repro.obs.slo import PauseAccount, SLOLedger, WindowAttainment, meets_slo
 from repro.obs.trace import (
     Span,
     TraceBuffer,
+    annotate,
     export_chrome,
     overlaps,
     validate_chrome,
@@ -81,5 +86,6 @@ __all__ = [
     "TTFT_COMPONENTS", "TPOT_COMPONENTS",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "RequestAggregate",
     "PauseAccount", "SLOLedger", "WindowAttainment", "meets_slo",
-    "Span", "TraceBuffer", "export_chrome", "overlaps", "validate_chrome",
+    "Span", "TraceBuffer", "annotate", "export_chrome", "overlaps",
+    "validate_chrome",
 ]

@@ -38,7 +38,7 @@ import time  # swapped for the installed clock by install_clock
 from typing import Any, Callable, Dict, Iterator, List, Mapping, Optional
 
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import Span, TraceBuffer, export_chrome
+from repro.obs.trace import Span, TraceBuffer, annotate, export_chrome
 
 
 def now() -> float:
@@ -135,17 +135,12 @@ class Recorder:
     Args:
         capacity: event-bus ring size.
         trace_capacity: span-ring size.
-        decode_stride: engines emit an ``engine.decode`` progress event
-            every this-many decode steps (1 == every step; bounded
-            volume is the default).
     """
 
-    def __init__(self, capacity: int = 65536, trace_capacity: int = 65536,
-                 decode_stride: int = 16):
+    def __init__(self, capacity: int = 65536, trace_capacity: int = 65536):
         self.bus = EventBus(capacity)
         self.trace = TraceBuffer(trace_capacity)
         self.metrics = MetricsRegistry()
-        self.decode_stride = max(1, int(decode_stride))
 
     # -- events --------------------------------------------------------
     def emit(self, kind: str, *, engine: str = "", rid: int = -1,
@@ -189,10 +184,12 @@ class Recorder:
     def span(self, name: str, track: str = "main", cat: str = "serving",
              **args: Any) -> Iterator[Dict[str, Any]]:
         """Record the body as one span; mutate the yielded dict to add
-        result args (they land in the exported trace)."""
+        result args (they land in the exported trace). The body is also
+        a profiler span of the same name (`repro.obs.trace.annotate`)."""
         t0 = now()
         try:
-            yield args
+            with annotate(name):
+                yield args
         finally:
             self.trace.add(Span(name, t0, max(0.0, now() - t0),
                                 track, cat, args))

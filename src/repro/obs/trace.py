@@ -1,10 +1,18 @@
-"""Span tracing for the flight recorder: bounded span storage plus a
-Chrome ``trace_event`` JSON exporter.
+"""Span tracing: profiler annotations on the device trace's clock, and
+the flight recorder's bounded span storage plus a Chrome
+``trace_event`` JSON exporter.
 
-Spans are closed intervals on the *recording clock* (the clock installed
-into the serving layer — see `repro.serving.clock.install_clock`; the
-recorder reads it non-advancing, so tracing never perturbs a simulated
-run). A span belongs to a ``track`` — an engine name or a subsystem like
+`annotate` puts a span into the `jax.profiler` trace, where its host
+timestamps share the clock of the device's operations. The serving path
+calls it unconditionally at each layer boundary of a step (the names are
+listed in docs/observability.md, "Profiler spans"): with no profiler
+session it costs about a microsecond.
+
+The recorder's spans are closed intervals on the *recording clock* (the
+clock installed into the serving layer — see
+`repro.serving.clock.install_clock`; the recorder reads it
+non-advancing, so tracing never perturbs a simulated run). A span
+belongs to a ``track`` — an engine name or a subsystem like
 ``"cluster"`` / ``"planner"`` — which the exporter maps onto Chrome
 ``tid`` lanes, so a Perfetto timeline shows one row per engine with the
 swap windows, migration pauses, and routing decisions nested on it.
@@ -20,6 +28,15 @@ import dataclasses
 import json
 import threading
 from typing import Any, Dict, List, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
+
+
+def annotate(name: str, **args: Any) -> TraceAnnotation:
+    """A context manager that records its body as span ``name`` in the
+    profiler trace, with ``args`` as the event's stats. A no-op beyond
+    its construction when no profiler session is on."""
+    return TraceAnnotation(name, **args)
 
 
 @dataclasses.dataclass(frozen=True)

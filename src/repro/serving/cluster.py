@@ -57,6 +57,7 @@ import numpy as np
 
 from repro.obs import events as obs_events
 from repro.obs.metrics import RequestAggregate
+from repro.obs.trace import annotate
 from repro.serving.engine import (
     Request,
     ServingEngine,
@@ -676,16 +677,19 @@ class ServingCluster:
         engine (all are past their first token — prefill emits it at
         admission) is handed to a decode-role engine through the batched
         migration path (`_handoff_ready`)."""
-        self._commit_ready()
-        n = 0
-        with self._step_lock:     # a commit never lands mid-decode
-            for e in list(self._entries.values()):
-                if not e.engine.paused:
-                    n += e.engine.step()
-        self._handoff_ready()
-        with self._lock:
-            self._reap_drained()
-        return n
+        with annotate("cluster.step"):
+            with annotate("cluster.commit_ready"):
+                self._commit_ready()
+            n = 0
+            with self._step_lock:     # a commit never lands mid-decode
+                for e in list(self._entries.values()):
+                    if not e.engine.paused:
+                        n += e.engine.step()
+            with annotate("cluster.handoff"):
+                self._handoff_ready()
+                with self._lock:
+                    self._reap_drained()
+            return n
 
     def handoff_ready(self) -> List[MigrationRecord]:
         """Public handoff hook: move every handoff-eligible request from
@@ -1226,7 +1230,8 @@ class ServingCluster:
             entry.swapping = True
             t0 = time.time()
             try:
-                with self._step_lock:   # never lands mid-decode-step
+                # never lands mid-decode-step
+                with self._step_lock, annotate("cluster.swap_commit"):
                     eng.pause()
                     try:
                         eng.drain()
@@ -1660,7 +1665,7 @@ class ServingCluster:
         """
         if src == dst:
             raise ValueError("source and destination are the same engine")
-        with self._lock:
+        with self._lock, annotate("cluster.migrate"):
             return self._migrate_locked(src, dst, rids, reason=reason)
 
     def _migrate_locked(self, src: str, dst: str,

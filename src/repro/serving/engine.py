@@ -39,6 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.models import Model
 from repro.obs import events as obs_events
+from repro.obs.trace import annotate
 from repro.serving import kvpool, migration
 from repro.serving.migration import MigrationError, SlotSnapshot
 from repro.sharding.plan import ShardingPlan, default_plan
@@ -64,8 +65,9 @@ class Request:
             pool's sequence capacity.
         labels: tenancy labels (e.g. ``{"data-type": "phi"}``) — the
             cluster routes and aggregates on these.
-        t_submit / t_first / t_done: wall-clock stamps set by the engine
-            at submission, first token, and completion.
+        t_submit / t_admit / t_first / t_done: serving-clock stamps set
+            by the engine at submission, when admission takes the
+            request off the queue, at the first token, and at completion.
         tokens_out: generated token ids (first entry comes from prefill).
     """
 
@@ -75,6 +77,7 @@ class Request:
     labels: Dict[str, str] = dataclasses.field(default_factory=dict)
     # metrics
     t_submit: float = 0.0
+    t_admit: float = 0.0
     t_first: float = 0.0
     t_done: float = 0.0
     tokens_out: List[int] = dataclasses.field(default_factory=list)
@@ -685,6 +688,9 @@ class ServingEngine:
                 except kvpool.PoolOOM:
                     return    # fail closed: stays queued, FIFO order kept
             req = self.queue.pop(0)
+            # non-advancing read of t_submit's clock: a stamp that only
+            # measures never moves a simulated run
+            req.t_admit = obs_events.now()
             S = len(req.prompt)
             # inputs are built on the host (numpy): an eager device op
             # here would compile per prompt length on the serving path
@@ -717,21 +723,27 @@ class ServingEngine:
                 # (never read: decode masks by position)
                 row = pages + [kvpool.SCRATCH_PAGE] \
                     * (self.pages_per_seq - len(pages))
-            t_pre0 = obs_events.now() if rec is not None else 0.0
-            if self.paged:
-                logits, self.cache = prefill(self.params, batch, self.cache,
-                                             np.asarray(row, np.int32))
-            else:
-                logits, cache1 = prefill(self.params, batch)
-            tok = int(np.argmax(
-                np.asarray(logits)[0, : self.vocab].astype(np.float32)))
-            t_pre1 = obs_events.now() if rec is not None else 0.0
+            with annotate("engine.prefill", prompt_len=S,
+                          bucket=batch["tokens"].shape[1]):
+                t_pre0 = obs_events.now() if rec is not None else 0.0
+                if self.paged:
+                    logits, self.cache = prefill(
+                        self.params, batch, self.cache,
+                        np.asarray(row, np.int32))
+                else:
+                    logits, cache1 = prefill(self.params, batch)
+                with annotate("engine.logits"):
+                    logits = np.asarray(logits)
+                with annotate("engine.sample"):
+                    tok = int(np.argmax(
+                        logits[0, : self.vocab].astype(np.float32)))
+                t_pre1 = obs_events.now() if rec is not None else 0.0
             req.tokens_out.append(tok)
             req.t_first = time.time()
             if rec is not None:
                 rec.emit("request.admit", engine=self.obs_name, rid=req.rid,
                          label=req.labels.get("data-type", ""),
-                         queue_wait_s=req.t_first - req.t_submit,
+                         queue_wait_s=req.t_admit - req.t_submit,
                          admit_s=max(0.0, t_pre0 - t_adm0),
                          prefill_s=max(0.0, t_pre1 - t_pre0),
                          role=self.role)
@@ -969,56 +981,63 @@ class ServingEngine:
         """
         if self.paused:
             raise EngineStateError("engine is paused (resume() to serve)")
-        self._admit()
-        if self.paged:
-            self._compact()
-        active = [i for i, r in enumerate(self.slot_req) if r is not None]
-        if not active:
-            return 0
-        tokens = np.zeros((self.n_slots, 1), dtype=np.int32)
-        for i in active:
-            tokens[i, 0] = self.slot_req[i].tokens_out[-1]
-        # per-slot positions (inactive slots write harmlessly at index 0 —
-        # their slot is re-prefilled before reuse; paged inactive lanes
-        # point at the scratch page)
-        pos = jnp.asarray(self.slot_pos, dtype=jnp.int32)
-        with self._exec_lock:
-            decode = self._decode_exec or self._decode
-        if self.paged:
-            if self._tables_dev is None:
-                self._tables_dev = jnp.asarray(self.page_tables)
-            logits, self.cache = decode(self.params, jnp.asarray(tokens),
-                                        self.cache, pos, self._tables_dev)
-        else:
-            logits, self.cache = decode(self.params, jnp.asarray(tokens),
-                                        self.cache, pos)
-        # vocab slice and argmax on the host: no eager device op (and so
-        # no compile) on the serving path
-        logits = np.asarray(logits)[:, : self.vocab].astype(np.float32)
-        now = time.time()
-        rec = obs_events.RECORDER
-        for i in active:
-            req = self.slot_req[i]
-            tok = int(np.argmax(logits[i]))
-            req.tokens_out.append(tok)
-            self.slot_pos[i] += 1
-            if (len(req.tokens_out) >= req.max_new_tokens
-                    or self.slot_pos[i] >= self.s_max - 1):
-                req.t_done = now
-                self.done.append(req)
-                self._release_lane(i)
-                if rec is not None:
-                    rec.emit("request.complete", engine=self.obs_name,
-                             rid=req.rid,
-                             label=req.labels.get("data-type", ""),
-                             ttft_s=req.ttft, tpot_s=req.tpot,
-                             tokens_out=len(req.tokens_out),
-                             role=self.role)
-        self.steps += 1
-        if rec is not None and self.steps % rec.decode_stride == 0:
-            rec.emit("engine.decode", engine=self.obs_name,
-                     step=self.steps, active=len(active))
-        return len(active)
+        with annotate("engine.step"):
+            with annotate("engine.admit"):
+                self._admit()
+            if self.paged:
+                with annotate("engine.compact"):
+                    self._compact()
+            active = [i for i, r in enumerate(self.slot_req)
+                      if r is not None]
+            if not active:
+                return 0
+            with annotate("engine.decode"):
+                tokens = np.zeros((self.n_slots, 1), dtype=np.int32)
+                for i in active:
+                    tokens[i, 0] = self.slot_req[i].tokens_out[-1]
+                # per-slot positions (inactive slots write harmlessly at
+                # index 0 — their slot is re-prefilled before reuse; paged
+                # inactive lanes point at the scratch page)
+                pos = jnp.asarray(self.slot_pos, dtype=jnp.int32)
+                with self._exec_lock:
+                    decode = self._decode_exec or self._decode
+                if self.paged:
+                    if self._tables_dev is None:
+                        self._tables_dev = jnp.asarray(self.page_tables)
+                    logits, self.cache = decode(
+                        self.params, jnp.asarray(tokens), self.cache, pos,
+                        self._tables_dev)
+                else:
+                    logits, self.cache = decode(
+                        self.params, jnp.asarray(tokens), self.cache, pos)
+            with annotate("engine.logits"):
+                jax.block_until_ready(logits)
+                logits = np.asarray(logits)
+            # vocab slice and argmax on the host: no eager device op (and
+            # so no compile) on the serving path
+            with annotate("engine.sample"):
+                logits = logits[:, : self.vocab].astype(np.float32)
+                now = time.time()
+                rec = obs_events.RECORDER
+                for i in active:
+                    req = self.slot_req[i]
+                    tok = int(np.argmax(logits[i]))
+                    req.tokens_out.append(tok)
+                    self.slot_pos[i] += 1
+                    if (len(req.tokens_out) >= req.max_new_tokens
+                            or self.slot_pos[i] >= self.s_max - 1):
+                        req.t_done = now
+                        self.done.append(req)
+                        self._release_lane(i)
+                        if rec is not None:
+                            rec.emit("request.complete",
+                                     engine=self.obs_name, rid=req.rid,
+                                     label=req.labels.get("data-type", ""),
+                                     ttft_s=req.ttft, tpot_s=req.tpot,
+                                     tokens_out=len(req.tokens_out),
+                                     role=self.role)
+                self.steps += 1
+            return len(active)
 
     def run(self, max_steps: int = 10_000) -> None:
         """Step until the queue and all slots are empty (or the engine's
