@@ -15,6 +15,7 @@ import jax
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import moe_dispatch as _moe
+from repro.kernels import paged_attention as _pa
 from repro.kernels import ssd_scan as _ssd
 
 
@@ -50,3 +51,14 @@ def moe_topk(logits, k: int, *, norm_topk: bool = False,
              block: int = _moe.DEFAULT_BLOCK) -> Tuple[jax.Array, jax.Array]:
     return _moe.moe_topk(logits, k, norm_topk=norm_topk, block=block,
                          interpret=_interpret())
+
+
+def paged_attention(q, k_pages, v_pages, k_new, v_new, lengths, tables,
+                    layer, *,
+                    pages_per_block: int = _pa.DEFAULT_PAGES_PER_BLOCK
+                    ) -> jax.Array:
+    """Not jitted itself: it is traced inside the decode step's own jit,
+    and the backend is read when that step is traced."""
+    return _pa.paged_attention(q, k_pages, v_pages, k_new, v_new, lengths,
+                               tables, layer, pages_per_block=pages_per_block,
+                               interpret=_interpret())

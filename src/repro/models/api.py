@@ -61,6 +61,14 @@ class Model:
             return encdec.decode_step(self.cfg, params, tokens, cache, pos)
         return lm.decode_step(self.cfg, params, tokens, cache, pos)
 
+    def paged_decode_step(self, params: PyTree, tokens: jax.Array,
+                          store: PyTree, pos: jax.Array, tables: jax.Array
+                          ) -> Tuple[jax.Array, PyTree]:
+        if self._is_encdec:
+            raise ValueError("paged decode is for decoder-only models")
+        return lm.paged_decode_step(self.cfg, params, tokens, store, pos,
+                                    tables)
+
     def init_cache(self, batch: int, s_max: int, dtype=jnp.bfloat16,
                    enc_len: Optional[int] = None) -> PyTree:
         if self._is_encdec:

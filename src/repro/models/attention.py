@@ -9,11 +9,13 @@ Modes:
   train   — full-sequence causal (or bidirectional), no cache I/O
   prefill — full-sequence causal, returns the populated cache
   decode  — q_len==1 at position `pos`, reads+updates the cache
+  paged_decode — (GQA) q_len==1 over a `PagedKV` page store, read in place
+            by the paged-attention kernel; returns the new entry only
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +32,17 @@ from repro.models.common import (
 )
 
 Cache = Dict[str, jax.Array]
+
+
+class PagedKV(NamedTuple):
+    """One layer's view of the KV pool's page store for ``paged_decode``:
+    the whole ``(L, P, page_size, Hkv, Dh)`` store leaves, the layer to
+    read, and the lanes' ``(B, pages_per_seq)`` page tables."""
+
+    k: jax.Array
+    v: jax.Array
+    layer: jax.Array
+    tables: jax.Array
 
 # above this sequence length, causal attention uses the chunked
 # online-softmax path (never materializes S x S logits)
@@ -186,6 +199,17 @@ def gqa_attention(
             v_cache = cache["v"].at[bidx, pos].set(v[:, 0].astype(cache["v"].dtype))
         new_cache = {"k": k_cache, "v": v_cache}
         out = sdpa(q, k_cache, v_cache, scale=scale, causal=False, kv_len=pos + 1)
+    elif mode == "paged_decode":
+        # `cache` is a PagedKV; `pos` (B,) counts each lane's entries
+        # already in the store. The new entry is attended from here and
+        # returned for the caller to write after the layer scan.
+        assert isinstance(cache, PagedKV) and S == 1
+        from repro.kernels import ops as kops
+        k_new = k[:, 0].astype(cache.k.dtype)
+        v_new = v[:, 0].astype(cache.v.dtype)
+        out = kops.paged_attention(q[:, 0], cache.k, cache.v, k_new, v_new,
+                                   pos, cache.tables, cache.layer)[:, None]
+        new_cache = {"k": k_new, "v": v_new}
     else:
         raise ValueError(mode)
 

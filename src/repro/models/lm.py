@@ -403,3 +403,41 @@ def decode_step(
         cfg, params, tokens, mode="decode", cache=cache, pos=pos, remat=False)
     logits = logits_fn(cfg, params, hidden[:, 0:1, :])[:, 0, :]
     return logits, new_cache
+
+
+def paged_decode_step(
+    cfg: ModelConfig,
+    params: PyTree,
+    tokens: jax.Array,      # (B, 1)
+    store: PyTree,          # {"k", "v"}: (L, P, page_size, Hkv, Dh) pages
+    pos: jax.Array,         # (B,) int32 — entries already in the store
+    tables: jax.Array,      # (B, pages_per_seq) int32
+) -> Tuple[jax.Array, PyTree]:
+    """One serving step over a paged KV store (GQA models): each layer's
+    attention reads the lanes' pages in place (`attention.PagedKV`), so
+    the store is closed over by the layer scan, never sliced or restacked
+    by it. Returns (logits (B, V), the new entries ``{"k", "v"}`` each
+    (L, B, Hkv, Dh)); the caller writes them into the store.
+    """
+    B = tokens.shape[0]
+    kinds = layer_kinds(cfg)
+    assert len(kinds) == 1 and kinds[0][0] == "attn", kinds
+    x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.dtype(cfg.activ_dtype))
+    x = constrain(x, "batch", None, None)
+    pos = jnp.asarray(pos, dtype=jnp.int32)
+    positions = pos[:, None]
+    if cfg.pos_type == "mrope":
+        positions = jnp.broadcast_to(positions, (3, B, 1))
+
+    def body(x, step_in):
+        lp, layer = step_in
+        kv = attn.PagedKV(store["k"], store["v"], layer, tables)
+        x, new_kv, _ = _run_sublayer(
+            cfg, lp, kinds[0], x, positions=positions, mode="paged_decode",
+            cache=kv, pos=pos, use_kernel=True)
+        return constrain(x, "batch", None, None), new_kv
+
+    layers = jnp.arange(n_scan_steps(cfg), dtype=jnp.int32)
+    x, new_kv = jax.lax.scan(body, x, (params["layers"], layers))
+    x = apply_norm(cfg, params["final_norm"], x)
+    return logits_fn(cfg, params, x)[:, 0, :], new_kv

@@ -232,8 +232,10 @@ class ServingEngine:
             # the host copy changes (tables are stable across pure-decode
             # steps, so steady-state decode pays no host->device transfer)
             self._tables_dev: Optional[jnp.ndarray] = None
-            self._paged_fn = kvpool.make_paged_decode(model, self._pax,
-                                                      self._sax)
+            # "pages" (the paged-attention kernel) or "dense" (gather);
+            # re-read whenever the store moves (`swap_plan`)
+            self.kv_path = kvpool.decode_path(model, _shardings(self.cache))
+            self._paged_fn = self._make_paged_decode(self.kv_path)
             self._paged_prefill_fn = kvpool.make_paged_prefill(
                 model, self._pax, self._sax)
         else:
@@ -270,6 +272,10 @@ class ServingEngine:
         # selection: a background PREPARE may commit (swap_plan) from a
         # control thread while step()/_admit() pick executables
         self._exec_lock = threading.Lock()
+
+    def _make_paged_decode(self, kv_path: str) -> Callable:
+        return kvpool.make_paged_decode(self.model, self._pax, self._sax,
+                                        pages=kv_path == "pages")
 
     @property
     def role(self) -> str:
@@ -353,6 +359,13 @@ class ServingEngine:
             self._migration_warm = False   # pool-surgery ops too
             if self.paged:
                 self._tables_dev = None    # re-place beside the new cache
+                kv_path = kvpool.decode_path(self.model,
+                                             _shardings(self.cache))
+                if kv_path != self.kv_path:
+                    self.kv_path = kv_path
+                    self._paged_fn = self._make_paged_decode(kv_path)
+                    self._decode = jax.jit(self._paged_fn,
+                                           donate_argnums=(2,))
         if executables:
             with self._exec_lock:
                 pf = executables.get("prefill")
@@ -466,7 +479,9 @@ class ServingEngine:
         out_sh = (_replicated(shardings["cache"]), shardings["cache"])
         if self.paged:
             tbl_sds = sds((self.n_slots, self.pages_per_seq), jnp.int32)
-            decode = jax.jit(self._paged_fn, donate_argnums=(2,),
+            fn = self._make_paged_decode(
+                kvpool.decode_path(self.model, shardings["cache"]))
+            decode = jax.jit(fn, donate_argnums=(2,),
                              out_shardings=out_sh) \
                 .lower(p_sds, tok_sds, c_sds, pos_sds, tbl_sds).compile()
         else:
@@ -991,7 +1006,16 @@ class ServingEngine:
                       if r is not None]
             if not active:
                 return 0
-            with annotate("engine.decode"):
+            if not self.paged:
+                span_args = {}
+            elif self.kv_path == "pages":    # pages through each new entry
+                span_args = {"kv_path": "pages", "kv_pages": sum(
+                    (int(self.slot_pos[i]) + self.page_size)
+                    // self.page_size for i in active)}
+            else:
+                span_args = {"kv_path": "dense",
+                             "kv_pages": self.n_slots * self.pages_per_seq}
+            with annotate("engine.decode", **span_args):
                 tokens = np.zeros((self.n_slots, 1), dtype=np.int32)
                 for i in active:
                     tokens[i, 0] = self.slot_req[i].tokens_out[-1]
@@ -1054,6 +1078,10 @@ class ServingEngine:
     def metrics(self) -> Dict[str, float]:
         """Full `METRIC_KEYS` summary over everything completed so far."""
         return compute_metrics(self.done)
+
+
+def _shardings(tree: PyTree) -> PyTree:
+    return jax.tree.map(lambda x: x.sharding, tree)
 
 
 def _replicated(shardings: PyTree) -> Any:

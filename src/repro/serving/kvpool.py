@@ -24,22 +24,46 @@ that layout with a vLLM-style paged pool:
                  request queued; nothing is evicted, nothing is dropped.
 
 The decode step stays shape-static (the engines' no-JIT-on-the-serving-
-path contract): `gather_pages` assembles the active rows' pages into a
-dense ``(B, pages_per_seq * page_size)`` cache, the model's unmodified
-``decode_step`` runs on it, and `scatter_token` writes the one new KV
-entry per row back through the page table. Gather/scatter are fused into
-a single jitted (or AOT-compiled) executable by the engine.
+path contract) and has two paths, fused into one jitted (or
+AOT-compiled) executable named ``paged_decode`` (`make_paged_decode`):
 
-Why garbage pages are harmless (the bitwise-identity argument): a page
-table row is padded with page 0 beyond the request's reserved extent,
-so the gathered dense cache holds scratch/garbage there — but decode
-attention masks every position ``>= pos`` by replacing its logit with
-``-1e30`` *before* the fp32 softmax (see ``repro.models.attention``), so
-masked lanes contribute exactly-zero weight whether the backing memory
-holds zeros or a retired request's stale KV. Token streams are therefore
-bitwise identical to the slot-granular engine's whenever the dense shape
-matches (``s_max`` a multiple of ``page_size``) — the property
-`benchmarks/live_migration.py` and tests/test_kvpool.py gate on.
+    pages   (GQA models, store on one TPU device) the layer scan closes
+            over the store and the paged-attention kernel
+            (`repro.kernels.paged_attention`) reads each lane's live pages
+            in place through its page table; the layers' new K/V entries
+            are the scan's only output and are written into the donated
+            store with one scatter after it. No dense cache exists.
+    gather  (every other pageable model: MLA, a store sharded over several
+            devices, the CPU backend) `gather_pages` assembles the lanes'
+            pages into a dense ``(B, pages_per_seq * page_size)`` cache,
+            the model's unmodified ``decode_step`` runs on it, and
+            `scatter_token` writes the one new entry per lane back.
+
+`decode_path` picks the path from the model and the store's placement;
+the engine asks it whenever it builds or compiles its decode. MLA keeps
+the gather path until a kernel reads its latent cache; a sharded store
+keeps it because the kernel is a single-device program.
+
+Why garbage pages are harmless: a page table row is padded with page 0
+beyond the request's reserved extent, so the gathered dense cache holds
+scratch/garbage there — but decode attention masks every position
+``>= pos`` by replacing its logit with ``-1e30`` *before* the fp32
+softmax (see ``repro.models.attention``), so masked positions contribute
+exactly-zero weight whether the backing memory holds zeros or a retired
+request's stale KV. The kernel masks the same positions inside a lane's
+last page and copies no page past it.
+
+What is bitwise, and against what:
+
+    gather path vs the slot-granular engine: bitwise identical token
+        streams whenever the dense shape matches (``s_max`` a multiple
+        of ``page_size``) — the property `benchmarks/live_migration.py`
+        and tests/test_kvpool.py gate on.
+    pages path vs gather path: equal within float tolerance only (the
+        kernel's online softmax accumulates in another order).
+    pages path vs pages path: bitwise, whatever physical pages back a
+        request, so a migration between two pages-path engines leaves
+        its stream unchanged.
 
 Paging is sound exactly where padded prefill is: every mixer must index
 KV by position (attn/MLA). SSM mixers carry recurrent state with no
@@ -266,6 +290,17 @@ def gather_pages(store: PyTree, tables: jnp.ndarray,
     return jax.tree.map(one, store, pax, sax)
 
 
+def _write_entry(leaf, tok, tables, pos, p, s):
+    """Set one entry per row: ``tok``'s row ``b`` goes to page
+    ``tables[b, pos[b] // page_size]``, offset ``pos[b] % page_size``."""
+    ps = leaf.shape[s]
+    idx = pos // ps                                       # (B,) page slot
+    phys = jnp.take_along_axis(tables, idx[:, None], axis=1)[:, 0]
+    off = pos % ps                                        # (B,) in-page
+    ix = (slice(None),) * p + (phys, off)
+    return leaf.at[ix].set(tok.astype(leaf.dtype))
+
+
 def scatter_token(store: PyTree, dense: PyTree, tables: jnp.ndarray,
                   pos: jnp.ndarray, pax: PyTree, sax: PyTree) -> PyTree:
     """Write each row's newest KV entry (position ``pos[b]`` of the
@@ -276,17 +311,12 @@ def scatter_token(store: PyTree, dense: PyTree, tables: jnp.ndarray,
     """
 
     def one(leaf, d, p, s):
-        ps = leaf.shape[s]
-        idx = pos // ps                                   # (B,) page slot
-        phys = jnp.take_along_axis(tables, idx[:, None], axis=1)[:, 0]
-        off = pos % ps                                    # (B,) in-page
         # each row's entry at its own pos: the index lives on the PAGE
         # (row) axis and selects one seq position per row
         sel = pos.reshape((1,) * p + (-1,) + (1,) * (d.ndim - p - 1))
         tok = jnp.take_along_axis(d, sel, axis=s)         # seq dim -> 1
-        tok = jnp.squeeze(tok, axis=s)
-        ix = (slice(None),) * p + (phys, off)
-        return leaf.at[ix].set(tok.astype(leaf.dtype))
+        return _write_entry(leaf, jnp.squeeze(tok, axis=s), tables, pos,
+                            p, s)
 
     return jax.tree.map(one, store, dense, pax, sax)
 
@@ -322,22 +352,55 @@ def write_pages(store: PyTree, single: PyTree, pages: Sequence[int],
     return jax.tree.map(one, store, single, pax, sax)
 
 
-def make_paged_decode(model, pax: PyTree, sax: PyTree):
+def supports_paged_kernel(model) -> bool:
+    """Whether the paged-attention kernel can serve the model's decode:
+    a decoder-only model whose every layer is GQA attention (``k``/``v``
+    cache leaves). MLA's latent cache has no kernel yet."""
+    from repro.models.lm import layer_kinds   # local: avoid cycles
+    cfg = model.cfg
+    return (cfg.encdec is None and not cfg.hybrid_period
+            and all(mixer == "attn" for mixer, _ in layer_kinds(cfg)))
+
+
+def decode_path(model, cache_shardings: PyTree) -> str:
+    """The paged decode path for a store placed on ``cache_shardings``:
+    ``"pages"`` (the kernel) for a GQA model whose store lies on one TPU
+    device, else ``"dense"`` (the gather path)."""
+    devices = set()
+    for sh in jax.tree.leaves(cache_shardings):
+        devices |= set(sh.device_set)
+    one_tpu = len(devices) == 1 and next(iter(devices)).platform == "tpu"
+    return "pages" if one_tpu and supports_paged_kernel(model) else "dense"
+
+
+def make_paged_decode(model, pax: PyTree, sax: PyTree, *,
+                      pages: bool = False):
     """The fused paged decode step (one jittable function — the engine's
-    AOT unit): gather the active rows' pages into a dense cache, run the
-    model's unmodified ``decode_step``, scatter the one new token per
-    row back through the page tables.
+    AOT unit), on the path `decode_path` names: with ``pages`` the
+    kernel reads the store in place and the new entries are scattered
+    into it after the layer scan; without, the gather path (gather the
+    lanes' pages into a dense cache, the model's unmodified
+    ``decode_step``, scatter the one new token per row back).
 
     Signature (cache at position 2, matching the slot engine's
     ``donate_argnums=(2,)`` contract so the store is donated through
     every step): ``(params, tokens (B,1), store, pos (B,), tables
     (B, pages_per_seq)) -> (logits, new_store)``.
     """
-
-    def paged_decode(params, tokens, store, pos, tables):
-        dense = gather_pages(store, tables, pax, sax)
-        logits, dense = model.decode_step(params, tokens, dense, pos)
-        return logits, scatter_token(store, dense, tables, pos, pax, sax)
+    if pages:
+        def paged_decode(params, tokens, store, pos, tables):
+            logits, new = model.paged_decode_step(params, tokens, store,
+                                                  pos, tables)
+            return logits, jax.tree.map(
+                lambda leaf, tok, p, s: _write_entry(leaf, tok, tables, pos,
+                                                     p, s),
+                store, new, pax, sax)
+    else:
+        def paged_decode(params, tokens, store, pos, tables):
+            dense = gather_pages(store, tables, pax, sax)
+            logits, dense = model.decode_step(params, tokens, dense, pos)
+            return logits, scatter_token(store, dense, tables, pos, pax,
+                                         sax)
 
     return paged_decode
 

@@ -131,7 +131,18 @@ def test_prefill_args_follow_the_bucket_ladder(traced):
     assert sorted(got) == sorted(
         (n, next(b for b in ladder if b >= n)) for n in PROMPTS)
     for s in walk(roots):                   # the only span args
-        assert not s.args or s.name == "engine.prefill"
+        assert not s.args or s.name in ("engine.prefill", "engine.decode")
+
+
+def test_decode_args_count_the_pages_read(traced):
+    """On the CPU the paged engine decodes on the gather path, which
+    reads every lane's whole page extent each step."""
+    eng, _, roots = traced
+    decodes = [s for s in walk(roots) if s.name == "engine.decode"]
+    assert decodes and eng.kv_path == "dense"
+    for s in decodes:
+        assert s.args == {"kv_path": "dense",
+                          "kv_pages": eng.n_slots * eng.pages_per_seq}
 
 
 def test_served_tokens_identical_with_the_profiler_on_and_off(

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import flash_attention as _fa
 from repro.kernels import moe_dispatch as _moe
+from repro.kernels import ops as _ops
 from repro.kernels import ssd_scan as _ssd
 from repro.models import build_model
 from repro.serving import kvpool
@@ -103,3 +104,28 @@ def test_minitron_paged_decode_fits_one_chip(one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES, mem
+
+
+def test_minitron_paged_kernel_decode_at_the_chat_cell_s_shapes(
+        one_chip, monkeypatch):
+    """The kernel path of the paged decode at the chat cell's shapes
+    (full-width minitron-4b, 24 lanes, s_max 768, 769 pages of 16): the
+    paged-attention kernel is in the program, and no dense copy of the
+    lanes' extents is (the gather path's temporaries there: 4.83 GB)."""
+    monkeypatch.setattr(_ops, "_interpret", lambda: False)   # compile it
+    model = build_model(get_config("minitron_4b"))
+    n_slots, s_max, page, n_store = 24, 768, 16, 769
+    pages_per_seq = s_max // page
+    pax, sax = kvpool.page_axes(model)
+    place = lambda t: jax.tree.map(                # noqa: E731
+        lambda x: _sds(one_chip, x.shape, x.dtype), t)
+    decode = jax.jit(kvpool.make_paged_decode(model, pax, sax, pages=True),
+                     donate_argnums=(2,))
+    compiled = decode.lower(
+        place(model.param_shapes()),
+        _sds(one_chip, (n_slots, 1), jnp.int32),
+        place(model.cache_shapes(n_store, page)),
+        _sds(one_chip, (n_slots,), jnp.int32),
+        _sds(one_chip, (n_slots, pages_per_seq), jnp.int32)).compile()
+    assert _mosaic(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25e9
